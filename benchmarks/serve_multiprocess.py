@@ -2,8 +2,9 @@
 coordinator/worker mesh vs a single-process engine.
 
 Same real-process-boundary requirement as ``serve_restart``: the pair is
-two fresh ``repro.launch.serve_vision`` processes (2 virtual CPU devices
-each, global universe of 4) joined through the coordination service on a
+two fresh ``repro.launch.serve_vision`` processes on the CPU (2 virtual
+devices each, global universe of 4 — a rehearsal of the control plane,
+never two processes on one chip) joined through the coordination service on a
 free local port; the reference is one fresh single-process launcher on a
 2-device mesh (same per-process device budget).  Both serve the same
 deterministic burst and report engine-measured served throughput
@@ -55,16 +56,30 @@ def _launch(extra, n_devices: int) -> subprocess.Popen:
                          + env.get("PYTHONPATH", ""))
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{n_devices}")
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
-    return subprocess.Popen(
+    env["JAX_PLATFORMS"] = "cpu"
+    logs = (tempfile.TemporaryFile("w+"), tempfile.TemporaryFile("w+"))
+    proc = subprocess.Popen(
         [sys.executable, "-m", "repro.launch.serve_vision",
          *COMMON, *extra],
-        env=env, cwd=ROOT,
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+        env=env, cwd=ROOT, stdout=logs[0], stderr=logs[1], text=True)
+    proc.logs = logs
+    return proc
+
+
+def _communicate(proc, timeout):
+    """Wait for a launcher; return its (stdout, stderr).  Output goes to
+    files, not pipes: a pipe drained only after the other process of the
+    pair exits fills up (XLA logs a long line per cache load) and blocks
+    its writer, and the pair deadlocks."""
+    proc.wait(timeout=timeout)
+    out, err = proc.logs
+    out.seek(0)
+    err.seek(0)
+    return out.read(), err.read()
 
 
 def _finish(proc: subprocess.Popen, name: str) -> None:
-    out, err = proc.communicate(timeout=1200)
+    out, err = _communicate(proc, 1200)
     if proc.returncode != 0:
         raise RuntimeError(f"{name} launcher failed "
                            f"(rc={proc.returncode}): {err[-2000:]}")
@@ -87,8 +102,7 @@ def run(backend: str = "xla"):
 
         port = _free_port()
         pair = ["--mesh", "2", "--coordinator", f"127.0.0.1:{port}",
-                "--num-processes", "2",
-                "--compilation-cache-dir", os.path.join(tmp, "cache")]
+                "--num-processes", "2"]
         coord_json = os.path.join(tmp, "coord.json")
         coord = _launch([*pair, "--process-id", "0",
                          "--json", coord_json], 2)

@@ -6,8 +6,11 @@ become servable (every reachable jit entry compiled) — and what the
 persistent compilation cache + warmup manifest buy on restart.  The
 measurement needs real process boundaries (the harness process has a
 long-lived jax whose in-memory jit cache would mask everything), so it
-launches ``repro.launch.serve_vision`` twice against one temp cache dir
-and reads ``compilation.warmup_ms`` from each run's ``--json`` snapshot:
+launches ``repro.launch.serve_vision`` twice on the CPU (a rehearsal: the
+harness process may hold the chip) against one cache dir of its own,
+``.jax_cache/bench_serve_restart`` in the checkout, emptied first and
+exported to both children as ``JAX_COMPILATION_CACHE_DIR``, and reads
+``compilation.warmup_ms`` from each run's ``--json`` snapshot:
 
 * ``serve_restart.cold_to_servable.xla`` — empty cache: warmup compiles
   every (model, bucket) entry and writes the manifest;
@@ -21,27 +24,31 @@ ratchet would turn runner drift into flakes.
 """
 import json
 import os
+import shutil
 import subprocess
 import sys
 import tempfile
 import time
 
 from benchmarks.common import emit
+from repro.launch.env import configure
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CACHE_DIR = os.path.join(ROOT, ".jax_cache", "bench_serve_restart")
 REQUESTS = 4
 
 
 def _serve_once(cache_dir: str, manifest: str, json_path: str) -> dict:
     """One fresh launcher process; returns (snapshot, wall_s)."""
-    env = dict(os.environ)
+    env = configure(platform="cpu", compilation_cache_dir=cache_dir,
+                    env=dict(os.environ))
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = (os.path.join(ROOT, "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
     t0 = time.perf_counter()
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve_vision",
          "--requests", str(REQUESTS), "--engine", "sync",
-         "--compilation-cache-dir", cache_dir,
          "--warmup-manifest", manifest, "--json", json_path],
         capture_output=True, text=True, timeout=1200, env=env, cwd=ROOT)
     wall_s = time.perf_counter() - t0
@@ -55,11 +62,11 @@ def _serve_once(cache_dir: str, manifest: str, json_path: str) -> dict:
 
 
 def run(backend: str = "xla"):
+    shutil.rmtree(CACHE_DIR, ignore_errors=True)   # the cold run is cold
     with tempfile.TemporaryDirectory(prefix="bench_restart_") as tmp:
-        cache_dir = os.path.join(tmp, "jax_cache")
         manifest = os.path.join(tmp, "warmup_manifest.json")
-        cold = _serve_once(cache_dir, manifest, os.path.join(tmp, "c.json"))
-        warm = _serve_once(cache_dir, manifest, os.path.join(tmp, "w.json"))
+        cold = _serve_once(CACHE_DIR, manifest, os.path.join(tmp, "c.json"))
+        warm = _serve_once(CACHE_DIR, manifest, os.path.join(tmp, "w.json"))
 
     cold_ms = float(cold["compilation"]["warmup_ms"])
     warm_ms = float(warm["compilation"]["warmup_ms"])

@@ -35,6 +35,7 @@ QUICKSTART_HELP = [
     [sys.executable, "-m", "repro.launch.serve_vision", "--help"],
     [sys.executable, "-m", "benchmarks.run", "--help"],
     [sys.executable, os.path.join("examples", "serve_vision.py"), "--help"],
+    [sys.executable, "chip_smoke.py", "--help"],
 ]
 QUICKSTART_MAKE = ["test", "test-fast", "bench-smoke", "restart-check",
                    "multiprocess-check", "docs-check", "ci"]

@@ -55,23 +55,40 @@ def free_port() -> int:
     return port
 
 
-def launch(extra, n_devices: int) -> subprocess.Popen:
-    """One launcher process with ``n_devices`` virtual CPU devices."""
+def launch(extra, n_devices: int, cache_dir: str) -> subprocess.Popen:
+    """One launcher process with ``n_devices`` virtual CPU devices (the
+    two-process mesh is a CPU rehearsal of the control plane), its
+    compilation cache in ``cache_dir`` through the environment."""
     env = dict(os.environ)
     env["PYTHONPATH"] = (os.path.join(ROOT, "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{n_devices}")
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
-    return subprocess.Popen(
+    env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+    logs = (tempfile.TemporaryFile("w+"), tempfile.TemporaryFile("w+"))
+    proc = subprocess.Popen(
         [sys.executable, "-m", "repro.launch.serve_vision",
          *COMMON, *extra],
-        env=env, cwd=ROOT,
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+        env=env, cwd=ROOT, stdout=logs[0], stderr=logs[1], text=True)
+    proc.logs = logs
+    return proc
+
+
+def communicate(proc, timeout):
+    """Wait for a launcher; return its (stdout, stderr).  Output goes to
+    files, not pipes: a pipe drained only after the other process of the
+    pair exits fills up (XLA logs a long line per cache load) and blocks
+    its writer, and the pair deadlocks."""
+    proc.wait(timeout=timeout)
+    out, err = proc.logs
+    out.seek(0)
+    err.seek(0)
+    return out.read(), err.read()
 
 
 def finish(proc: subprocess.Popen, name: str, timeout: int = 1200) -> None:
-    out, err = proc.communicate(timeout=timeout)
+    out, err = communicate(proc, timeout)
     if proc.returncode != 0:
         sys.stderr.write(f"--- {name} stdout ---\n{out[-2000:]}\n"
                          f"--- {name} stderr ---\n{err[-4000:]}\n")
@@ -93,23 +110,22 @@ def main() -> int:
     reqs = ["--requests", str(args.requests)]
     with tempfile.TemporaryDirectory(prefix="multiprocess_check_") as tmp:
         single_json = os.path.join(tmp, "single.json")
-        finish(launch([*reqs, "--mesh", "4",
-                       "--compilation-cache-dir",
-                       os.path.join(tmp, "cache_single"),
-                       "--json", single_json], 4), "single")
+        finish(launch([*reqs, "--mesh", "4", "--json", single_json], 4,
+                      os.path.join(tmp, "cache_single")), "single")
 
         port = free_port()
         pair = [*reqs, "--mesh", "2",
                 "--coordinator", f"127.0.0.1:{port}",
                 "--num-processes", "2",
-                "--compilation-cache-dir", os.path.join(tmp, "cache_pair"),
                 "--warmup-manifest", os.path.join(tmp, "manifest.json")]
+        pair_cache = os.path.join(tmp, "cache_pair")
         coord_json = os.path.join(tmp, "coord.json")
         worker_json = os.path.join(tmp, "worker.json")
-        coord = launch([*pair, "--process-id", "0", "--json", coord_json], 2)
+        coord = launch([*pair, "--process-id", "0", "--json", coord_json], 2,
+                       pair_cache)
         time.sleep(args.worker_delay)
         worker = launch([*pair, "--process-id", "1",
-                         "--json", worker_json], 2)
+                         "--json", worker_json], 2, pair_cache)
         finish(coord, "coordinator")
         finish(worker, "worker")
 

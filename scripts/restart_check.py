@@ -36,14 +36,18 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def run_serve(cache_dir: str, manifest: str, json_path: str,
               requests: int, engine: str) -> dict:
-    """One launcher process against ``cache_dir``; returns its metrics
-    snapshot (read from ``--json-path``)."""
-    env = dict(os.environ)
+    """One CPU launcher process against ``cache_dir``, exported to it as
+    ``JAX_COMPILATION_CACHE_DIR``; returns its metrics snapshot (read
+    from ``--json-path``)."""
+    sys.path.insert(0, os.path.join(ROOT, "src"))
+    from repro.launch.env import configure
+    env = configure(platform="cpu", compilation_cache_dir=cache_dir,
+                    env=dict(os.environ))
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = (os.path.join(ROOT, "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
     cmd = [sys.executable, "-m", "repro.launch.serve_vision",
            "--requests", str(requests), "--engine", engine,
-           "--compilation-cache-dir", cache_dir,
            "--warmup-manifest", manifest,
            "--json", json_path]
     proc = subprocess.run(cmd, capture_output=True, text=True,

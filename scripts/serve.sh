@@ -13,12 +13,12 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
-# pull the mesh size, compilation-cache dir, and multi-process topology
-# out of the args (0 = single device, no flag; empty cache dir = no
-# persistent cache — the cache dir must reach the environment shim too so
-# the persistence floors are zeroed before jax starts; the coordinator
-# trio is exported so worker children the caller spawns with this same
-# script join the same mesh)
+# pull the mesh size and multi-process topology out of the args (0 =
+# single device, no flag; the coordinator trio is exported so worker
+# children the caller spawns with this same script join the same mesh).
+# An exported JAX_COMPILATION_CACHE_DIR reaches the environment shim too,
+# so the persistence floors are zeroed before jax starts; unset, the
+# launcher keeps its cache in <checkout>/.jax_cache
 MESH=0
 CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-}"
 COORDINATOR="${JAX_COORDINATOR_ADDRESS:-}"
@@ -28,10 +28,6 @@ args=("$@")
 for ((i = 0; i < ${#args[@]}; i++)); do
     if [[ "${args[$i]}" == "--mesh" && $((i + 1)) -lt ${#args[@]} ]]; then
         MESH="${args[$((i + 1))]}"
-    fi
-    if [[ "${args[$i]}" == "--compilation-cache-dir" \
-          && $((i + 1)) -lt ${#args[@]} ]]; then
-        CACHE_DIR="${args[$((i + 1))]}"
     fi
     if [[ "${args[$i]}" == "--coordinator" \
           && $((i + 1)) -lt ${#args[@]} ]]; then

@@ -29,12 +29,36 @@ Array = jax.Array
 # Primitive convolutions (NHWC).
 # ---------------------------------------------------------------------------
 
+def _pad_for(extent: int, k: int, stride: int, padding: str):
+    """(out_len, pad_lo, pad_hi) of lax's 'SAME' (low side gets
+    ``pad_total // 2``) or 'VALID' padding."""
+    if padding == "VALID":
+        return (extent - k) // stride + 1, 0, 0
+    assert padding == "SAME", padding
+    out_len = -(-extent // stride)
+    pad_total = max(0, (out_len - 1) * stride + k - extent)
+    return out_len, pad_total // 2, pad_total - pad_total // 2
+
+
 def conv2d(x: Array, w: Array, *, stride: int = 1, padding: str = "SAME") -> Array:
-    """Standard convolution.  x: (B,H,W,Cin), w: (Kh,Kw,Cin,Cout)."""
-    return jax.lax.conv_general_dilated(
-        x, w, window_strides=(stride, stride), padding=padding,
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-    )
+    """Standard convolution.  x: (B,H,W,Cin), w: (Kh,Kw,Cin,Cout).
+
+    Computed as shifted-window patches @ weights (im2col): the same sum
+    as ``lax.conv_general_dilated``.  For a network's stem (batch 1, fp32
+    precision, a Pallas kernel reading the output) the TPU compiler took
+    over a minute on the direct convolution and seconds on this form.
+    """
+    kh, kw, cin, cout = w.shape
+    _, h, wd, _ = x.shape
+    oh, lo_h, hi_h = _pad_for(h, kh, stride, padding)
+    ow, lo_w, hi_w = _pad_for(wd, kw, stride, padding)
+    xp = jnp.pad(x, ((0, 0), (lo_h, hi_h), (lo_w, hi_w), (0, 0)))
+    taps = [xp[:, i:i + (oh - 1) * stride + 1:stride,
+               j:j + (ow - 1) * stride + 1:stride, :]
+            for i in range(kh) for j in range(kw)]
+    patches = jnp.concatenate(taps, axis=-1)      # features (kh, kw, cin)
+    return jnp.einsum("bhwp,po->bhwo", patches,
+                      w.reshape(kh * kw * cin, cout))
 
 
 def depthwise_conv2d(x: Array, w: Array, *, stride: int = 1,

@@ -6,10 +6,10 @@ Three ways to run the paper's operators:
                          lax convolutions).  Always available; the
                          correctness oracle for the others.
   * ``pallas``         — the Pallas kernels executed in ``interpret=True``
-                         mode (Python semantics on CPU — this container has
-                         no TPU).
-  * ``pallas_tpu``     — the same kernels with ``interpret=False``; wired for
-                         real TPU hardware, do not select on CPU.
+                         mode (Python semantics; CPU only — selecting it on
+                         an accelerator fails at the first kernel call).
+  * ``pallas_tpu``     — the same kernels with ``interpret=False``: compiled
+                         by Mosaic for the TPU.
 
 A ``Backend`` is a frozen value object threaded through
 ``repro.vision.zoo.apply_network`` (and anything else that executes
@@ -18,7 +18,8 @@ re-tracing logic scattered across call sites.  ``Backend.interpret`` is the
 ONLY source of truth for interpret-vs-compiled: kernel wrappers take
 ``interpret=None`` and resolve it via :func:`resolve_interpret`, so a call
 site that forgets to thread the flag gets the process default instead of a
-silently hardcoded ``True`` (which would make ``pallas_tpu`` interpret).
+silently hardcoded ``True`` (which would make ``pallas_tpu`` interpret),
+and the resolution refuses interpret mode off CPU.
 
 ``Backend.fused`` gates the fused FuSeConv megakernel
 (``repro.kernels.fused.fuseconv_fused``): on by default for the pallas
@@ -82,14 +83,23 @@ def resolve_backend(spec: Union[str, Backend, None]) -> Backend:
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """Resolve a kernel wrapper's ``interpret`` argument.
+    """Resolve a kernel's ``interpret`` argument where the kernel is called.
 
-    ``None`` means "nobody threaded a Backend here": fall back to the
-    process default, which is interpret mode — the safe choice on this
-    CPU container.  Call sites on the serving path must pass the resolved
-    ``Backend.interpret`` explicitly (pinned by the dispatch-spy test in
-    tests/test_backend_conformance.py) so ``pallas_tpu`` runs compiled.
+    Interpret mode runs only where JAX's platform is CPU: ``None`` (nobody
+    threaded a Backend here) resolves to ``True`` on CPU and to ``False``
+    (compiled) anywhere else, and an explicit ``True`` on an accelerator
+    is an error — a kernel that silently ran in the Python interpreter on
+    the chip would look like a working, very slow device path.  An
+    explicit ``False`` on CPU is allowed: it is how a kernel is compiled
+    for a described, unattached TPU (tests/test_tpu_compile.py).
     """
+    import jax
+    platform = jax.default_backend()
     if interpret is None:
-        return True
+        return platform == "cpu"
+    if interpret and platform != "cpu":
+        raise ValueError(
+            f"Pallas interpret mode was requested on platform {platform!r}; "
+            f"it runs only on CPU.  Select the 'pallas_tpu' backend (or "
+            f"pass interpret=False) to run the compiled kernels.")
     return bool(interpret)

@@ -22,10 +22,13 @@ paper's networks, 4 in RG-LRU / xLSTM front-ends).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import backend as kb
 
 DEFAULT_BLOCK_C = 128
 
@@ -41,15 +44,16 @@ def _fuse1d_kernel(x_ref, w_ref, y_ref, *, k: int, t: int):
 
 @functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
 def fuse1d(x_pad: jax.Array, w: jax.Array, *, block_c: int = DEFAULT_BLOCK_C,
-           interpret: bool = True) -> jax.Array:
+           interpret: Optional[bool] = None) -> jax.Array:
     """Bank of independent 1-D convolutions.
 
     x_pad: (N, T + K - 1, C) pre-padded inputs; w: (K, C).
     Returns y: (N, T, C) with y[n, t, c] = sum_k x_pad[n, t + k, c] * w[k, c].
 
-    ``interpret=True`` executes the kernel body in Python on CPU (this
-    container has no TPU); on TPU pass ``interpret=False``.
+    ``interpret`` resolves through ``backend.resolve_interpret``: the
+    Python interpreter on CPU, the compiled kernel on TPU.
     """
+    interpret = kb.resolve_interpret(interpret)
     n, tp, c = x_pad.shape
     k, cw = w.shape
     assert cw == c, (w.shape, x_pad.shape)

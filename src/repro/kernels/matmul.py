@@ -8,11 +8,14 @@ granularity).  128-aligned blocks map onto the 128x128 MXU.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import backend as kb
 
 
 def _matmul_kernel(a_ref, b_ref, y_ref, acc_ref, *, n_k: int):
@@ -20,8 +23,11 @@ def _matmul_kernel(a_ref, b_ref, y_ref, acc_ref, *, n_k: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # fp32 contraction on the MXU, as in interpret mode (the TPU default
+    # for f32 operands may round them to bf16)
     acc_ref[...] += jnp.dot(a_ref[...].astype(jnp.float32),
                             b_ref[...].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == n_k - 1)
@@ -33,12 +39,13 @@ def _matmul_kernel(a_ref, b_ref, y_ref, acc_ref, *, n_k: int):
                                              "interpret"))
 def matmul(a: jax.Array, b: jax.Array, *, block_m: int = 128,
            block_n: int = 128, block_k: int = 128,
-           interpret: bool = True) -> jax.Array:
+           interpret: Optional[bool] = None) -> jax.Array:
     """y = a @ b with fp32 VMEM-scratch accumulation.  a: (M,K), b: (K,N).
 
-    ``interpret=True`` runs the kernel body on CPU (no TPU in this
-    container); pass ``interpret=False`` on real hardware.
+    ``interpret`` resolves through ``backend.resolve_interpret``: the
+    Python interpreter on CPU, the compiled kernel on TPU.
     """
+    interpret = kb.resolve_interpret(interpret)
     m, k = a.shape
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)

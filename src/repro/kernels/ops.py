@@ -1,10 +1,11 @@
 """jit'd model-facing wrappers around the Pallas kernels.
 
-On this CPU container the kernels run in ``interpret=True`` mode (Python
-semantics, bit-equivalent block schedule); on TPU the resolved
-``Backend.interpret`` (False for ``pallas_tpu``) must be threaded through —
-every wrapper takes ``interpret=None`` meaning "resolve the process default"
-(``backend.resolve_interpret``), never a hardcoded mode.  The wrappers own
+On CPU the kernels run in ``interpret=True`` mode (Python semantics,
+bit-equivalent block schedule); on TPU the resolved ``Backend.interpret``
+(False for ``pallas_tpu``) must be threaded through — every wrapper takes
+``interpret=None`` meaning "resolve by platform"
+(``backend.resolve_interpret``: interpret on CPU only), never a hardcoded
+mode.  The wrappers own
 layout plumbing: padding, chunking long sequences into VMEM-sized tiles, and
 the 2-D row/column transposes that reduce FuSe-2D to the fuse1d primitive.
 

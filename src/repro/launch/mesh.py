@@ -108,15 +108,24 @@ def logical_universe(num_processes: int,
     return tuple(out)
 
 
+def _make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """``jax.make_mesh`` with Auto axes.  jax 0.9 defaults new meshes to
+    Explicit axis types, under which a gather over a sharded operand
+    (the embedding lookup in ``models/model.py``) must name its output
+    sharding; this repo relies on the compiler propagating it."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1x1 mesh on the real local device (CPU smoke paths)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _make_mesh((1, 1), ("data", "model"))
 
 
 def make_data_mesh(n_devices: int = 0):
@@ -126,7 +135,7 @@ def make_data_mesh(n_devices: int = 0):
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``."""
     n = n_devices or len(jax.devices())
     assert n <= len(jax.devices()), (n, len(jax.devices()))
-    return jax.make_mesh((n,), ("data",))
+    return _make_mesh((n,), ("data",))
 
 
 def make_multiprocess_data_mesh(num_processes: int, process_id: int,

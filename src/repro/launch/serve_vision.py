@@ -175,12 +175,6 @@ def main(argv=None):
                     help="drain synchronously on the caller's thread instead"
                          " of the pipelined executor (alias for"
                          " --engine sync)")
-    ap.add_argument("--compilation-cache-dir", default=None,
-                    help="persistent XLA compilation-cache directory"
-                         " (default: $JAX_COMPILATION_CACHE_DIR; unset ="
-                         " cache off).  Warmed jit entries persist here and"
-                         " a restarted process deserializes them instead of"
-                         " recompiling")
     ap.add_argument("--warmup-manifest", default=None,
                     help="warmup-manifest JSON path: persist the warmed"
                          " (model, bucket, group) set on cold start and"
@@ -210,8 +204,13 @@ def main(argv=None):
     if args.engine and args.sync and args.engine != "sync":
         raise SystemExit(f"--sync conflicts with --engine {args.engine}")
     engine_name = args.engine or ("sync" if args.sync else "pipelined")
-    cache_dir = (args.compilation_cache_dir
-                 or os.environ.get("JAX_COMPILATION_CACHE_DIR") or None)
+    # persistent compilation cache: $JAX_COMPILATION_CACHE_DIR where it
+    # is set, else the fixed <checkout>/.jax_cache.  Warmed jit entries
+    # persist there and a restarted process deserializes them instead of
+    # recompiling
+    from repro.serving.vision.compilecache import (DEFAULT_CACHE_DIR,
+                                                   resolve_cache_dir)
+    cache_dir = resolve_cache_dir(DEFAULT_CACHE_DIR)
 
     tenants = []
     for entry in args.tenant or []:
@@ -411,6 +410,12 @@ def main(argv=None):
         # engine drained first; then release workers and the runtime
         coord.stop_workers()
         shutdown_distributed()
+    # a request whose batch raised is a failed run; admission "rejected"
+    # and "shed" are policy outcomes and do not fail it
+    failed = [r.rid for r in results if r.status == "error"]
+    if failed:
+        raise SystemExit(f"{len(failed)} request(s) ended in status "
+                         f"'error': rids {failed}")
 
 
 if __name__ == "__main__":

@@ -8,9 +8,12 @@ fingerprint, so a restarted process that replays the same warmup set reads
 executables back instead of recompiling.  This module is the one place
 that turns the cache on and counts what it does:
 
-* :func:`enable_compilation_cache` resolves the cache directory (explicit
-  argument > ``JAX_COMPILATION_CACHE_DIR`` environment variable) and
-  applies the jax config knobs serving needs — crucially the
+* :func:`enable_compilation_cache` resolves the cache directory
+  (``JAX_COMPILATION_CACHE_DIR`` where it is set — it always wins — else
+  the caller's directory; entry points pass :data:`DEFAULT_CACHE_DIR`, a
+  fixed directory inside the checkout, never a temporary name: the path
+  is part of the cache key) and applies the jax config knobs serving
+  needs — crucially the
   min-compile-time / min-entry-size floors are dropped to zero, because
   the smoke models' per-entry compiles are far below jax's default 1 s
   persistence threshold and would silently never be written.
@@ -34,9 +37,15 @@ import threading
 from typing import Dict, Optional
 
 ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+# where entry points keep the cache when JAX_COMPILATION_CACHE_DIR is
+# unset: <checkout>/.jax_cache (listed in .gitignore)
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__)))))), ".jax_cache")
 
-# jax.monitoring event names (stable across jax 0.4.x; see
-# jax/_src/compiler.py and jax/_src/compilation_cache.py)
+# jax.monitoring event names, as emitted by jax/_src/compiler.py and
+# jax/_src/compilation_cache.py of the pinned jax (0.9.0).  They are
+# internals: re-check them when the pin moves.
 _EVENT_REQUESTS = "/jax/compilation_cache/compile_requests_use_cache"
 _EVENT_HITS = "/jax/compilation_cache/cache_hits"
 _EVENT_MISSES = "/jax/compilation_cache/cache_misses"
@@ -107,11 +116,22 @@ def counters_delta(before: Dict[str, float],
     return {k: after.get(k, 0) - before.get(k, 0) for k in after}
 
 
+def resolve_cache_dir(cache_dir: Optional[str] = None) -> Optional[str]:
+    """``JAX_COMPILATION_CACHE_DIR`` where it is set, else ``cache_dir``,
+    else None (cache off).  The variable wins so that whoever runs the
+    program places the cache, and no code sets another directory."""
+    resolved = os.environ.get(ENV_CACHE_DIR) or cache_dir or None
+    if not resolved:
+        return None
+    return os.path.abspath(os.path.expanduser(str(resolved)))
+
+
 def enable_compilation_cache(cache_dir: Optional[str] = None
                              ) -> Optional[str]:
     """Turn on jax's persistent compilation cache; returns the resolved
-    directory (created if missing), or None when no directory was given
-    and ``JAX_COMPILATION_CACHE_DIR`` is unset (cache stays off).
+    directory (created if missing, see :func:`resolve_cache_dir`), or None
+    when no directory was given and ``JAX_COMPILATION_CACHE_DIR`` is unset
+    (cache stays off).
 
     Must run before the entries it should capture are compiled — in
     practice the registry calls it at construction, well before any jit.
@@ -119,10 +139,9 @@ def enable_compilation_cache(cache_dir: Optional[str] = None
     different one, the later call wins (jax re-reads the config per
     compile).
     """
-    resolved = cache_dir or os.environ.get(ENV_CACHE_DIR) or None
+    resolved = resolve_cache_dir(cache_dir)
     if not resolved:
         return None
-    resolved = os.path.abspath(os.path.expanduser(str(resolved)))
     os.makedirs(resolved, exist_ok=True)
     import jax
     jax.config.update("jax_compilation_cache_dir", resolved)

@@ -11,16 +11,20 @@ Sharding: constructed with a ``jax.sharding`` mesh carrying a ``"data"``
 axis (see ``repro.launch.mesh.make_data_mesh``), the registry executes each
 batch data-parallel over a device group — params replicated over the group
 (``NamedSharding(mesh, P())``), the batch axis sharded over ``"data"`` when
-the bucket divides the group size, replicated otherwise (replication keeps
-per-example results bitwise-identical to the unsharded path; only the
-placement changes).  The jit cache key grows to ``(model key, bucket,
-device-group ids)`` and per-group parameter placements are cached, so the
+the bucket divides the group size, replicated otherwise; the forward pass
+runs under ``shard_map`` on each device's rows (or the whole replicated
+batch), since the TPU compiler cannot partition a Pallas kernel.  Per
+example the results are bitwise-identical to the unsharded path run at
+the batch shape each device sees (XLA may choose other kernels, and so
+round fp32 sums differently, for another batch size).  The jit cache key
+grows to ``(model key, bucket, device-group ids)`` and per-group
+parameter placements are cached, so the
 round scheduler's handful of power-of-two contiguous groups each compile
 exactly once.  Testable on CPU with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 
-Restarts: constructed with ``compilation_cache_dir`` (or with
-``JAX_COMPILATION_CACHE_DIR`` exported), the registry points jax's
+Restarts: with ``JAX_COMPILATION_CACHE_DIR`` exported (it wins) or
+constructed with ``compilation_cache_dir``, the registry points jax's
 persistent compilation cache at that directory (persistence floors
 zeroed — see ``compilecache.py``) so every jit entry built here is
 written to disk and a restarted process deserializes instead of
@@ -117,8 +121,8 @@ class ModelRegistry:
                 np.asarray(mesh.devices).flatten().tolist())
         else:
             self.devices = None
-        # persistent compilation cache: explicit dir > the
-        # JAX_COMPILATION_CACHE_DIR environment variable > off.  Enabled
+        # persistent compilation cache: the JAX_COMPILATION_CACHE_DIR
+        # environment variable > the given dir > off.  Enabled
         # here, at construction, so every jit entry this registry ever
         # builds is persisted (and restart-replayable)
         self.compilation_cache_dir = enable_compilation_cache(
@@ -166,13 +170,30 @@ class ModelRegistry:
         return list(self._models)
 
     # -- execution ----------------------------------------------------------
-    def _build_apply(self, model: RegisteredModel) -> Callable:
+    def _build_apply(self, model: RegisteredModel,
+                     group: Optional[Mesh] = None,
+                     shard: bool = False) -> Callable:
+        """jit of the model's forward pass.  On a device group of more
+        than one device the pass runs under ``shard_map``: with ``shard``
+        each device computes its own rows of the batch, otherwise every
+        device computes the whole (replicated) batch.  The TPU compiler
+        cannot partition a Pallas kernel by itself ("Mosaic kernels cannot
+        be automatically partitioned"), so the placement is spelled out."""
         net, variant, backend = model.net, model.variant, model.backend
 
         def apply(params, images):
-            logits, _ = zoo.apply_network(params, net, images, variant,
-                                          train=False, backend=backend)
+            # serve the f32 network at f32: on TPU the default precision
+            # of an f32 conv or dot rounds its operands to bf16, which
+            # moves seeded zoo logits by 1-24% of the largest (PERF.md)
+            with jax.default_matmul_precision("highest"):
+                logits, _ = zoo.apply_network(params, net, images, variant,
+                                              train=False, backend=backend)
             return logits
+
+        if group is not None and group.size > 1:
+            rows = P("data") if shard else P()
+            apply = jax.shard_map(apply, mesh=group, in_specs=(P(), rows),
+                                  out_specs=rows, check_vma=False)
 
         # Donate the batch input: it is dead after the call (the engine
         # pads into a fresh bucket array per round), so XLA may reuse its
@@ -245,8 +266,8 @@ class ModelRegistry:
         ``devices``: the device group to execute on (defaults to the whole
         mesh when one was given at construction, else the legacy
         single-device path).  The batch shards over the group when the
-        bucket divides it; otherwise it is replicated (bitwise-identical
-        results either way)."""
+        bucket divides it; otherwise it is replicated (see the module
+        docstring for what stays bitwise-identical)."""
         model = self._models[key]
         x = jnp.asarray(images)
         bucket = x.shape[0]
@@ -257,12 +278,13 @@ class ModelRegistry:
         devs = tuple(devices) if devices is not None else self.devices
         gmesh = self._group_mesh(devs)
         ids = tuple(d.id for d in devs)
-        spec = P("data") if len(devs) > 1 and bucket % len(devs) == 0 else P()
-        x = jax.device_put(x, NamedSharding(gmesh, spec))
+        shard = len(devs) > 1 and bucket % len(devs) == 0
+        x = jax.device_put(x, NamedSharding(gmesh, P("data") if shard
+                                            else P()))
         params = self._params_for(key, devs)
         cache_key = (key, bucket, ids)
         if cache_key not in self._jit:
-            self._jit[cache_key] = self._build_apply(model)
+            self._jit[cache_key] = self._build_apply(model, gmesh, shard)
         return self._call_entry(cache_key, self._jit[cache_key], params, x)
 
     def is_compiled(self, key: str, bucket: int,

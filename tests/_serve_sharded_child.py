@@ -23,6 +23,10 @@ import json  # noqa: E402
 
 import numpy as np  # noqa: E402
 
+# fp32 tolerance for one image's logits served in different batch shapes
+# (observed differences are below 3e-7 on tiny_net logits of order 1)
+FANBACK_TOL = dict(rtol=1e-5, atol=1e-5)
+
 
 def main() -> None:
     import jax
@@ -38,6 +42,14 @@ def main() -> None:
     mesh = make_data_mesh(8)
     rng = np.random.default_rng(0)
 
+    def unsharded(reg_u, key, x, per_device):
+        """The meshless path at the sharded run's per-device batch shape:
+        XLA may pick different kernels for different batch sizes, so the
+        bitwise comparison holds the batch shape each device sees fixed."""
+        return np.concatenate([
+            np.asarray(reg_u.apply(key, x[i:i + per_device]))
+            for i in range(0, len(x), per_device)])
+
     # -- operator-level parity: sharded vs unsharded, per backend ----------
     for backend in ("xla", "pallas"):
         reg_s = ModelRegistry(backend=backend, mesh=mesh)
@@ -45,20 +57,20 @@ def main() -> None:
         key = reg_s.register(net, "fuse_full").key
         reg_u.register(net, "fuse_full")
         # bucket 8 shards 1 image/device; bucket 4 does not divide 8 and
-        # runs replicated — both placements must be bitwise-identical to
-        # the meshless path
-        for bucket in (8, 4):
+        # runs replicated (4 images/device) — both placements must be
+        # bitwise-identical to the meshless path at that batch shape
+        for bucket, per_device in ((8, 1), (4, 4)):
             x = rng.standard_normal((bucket, 16, 16, 3)).astype(np.float32)
             sharded = np.asarray(reg_s.apply(key, x))
-            unsharded = np.asarray(reg_u.apply(key, x))
-            out[f"parity_{backend}_b{bucket}"] = bool(
-                np.array_equal(sharded, unsharded))
-        # half-mesh device group (the round scheduler's 2-group split)
+            out[f"parity_{backend}_b{bucket}"] = bool(np.array_equal(
+                sharded, unsharded(reg_u, key, x, per_device)))
+        # half-mesh device group (the round scheduler's 2-group split):
+        # bucket 4 over 4 devices, 1 image/device
         x = rng.standard_normal((4, 16, 16, 3)).astype(np.float32)
         grp = reg_s.devices[:4]
         out[f"parity_{backend}_group4"] = bool(np.array_equal(
             np.asarray(reg_s.apply(key, x, devices=grp)),
-            np.asarray(reg_u.apply(key, x))))
+            unsharded(reg_u, key, x, 1)))
 
     # -- engine end-to-end: cross-model rounds, fan-back ordering ----------
     reg = ModelRegistry(backend="xla", mesh=mesh)
@@ -83,15 +95,18 @@ def main() -> None:
     out["e2e_statuses_ok"] = all(r.status == "ok" for r in results)
     out["e2e_rid_order"] = [r.rid for r in results] == sorted(rids)
     # fan-back: every request's future must carry the logits of ITS OWN
-    # image (bitwise vs the unsharded single-image reference)
+    # image.  The reference runs the image alone (batch 1) while the
+    # engine batched it with others, so the comparison is at FANBACK_TOL:
+    # XLA may reorder fp32 sums for another batch shape (a few ulps), and
+    # another request's image differs by orders of magnitude more
     by_rid = {r.rid: r for r in results}
     fanback = True
     for rid, (k, img) in zip(rids, items):
         x = fit_image(np.asarray(img, np.float32), 16)[None]
         expect = np.asarray(ref.apply(k, x))[0]
-        if not np.array_equal(by_rid[rid].logits, expect):
+        if not np.allclose(by_rid[rid].logits, expect, **FANBACK_TOL):
             fanback = False
-    out["e2e_fanback_bitwise"] = fanback
+    out["e2e_fanback"] = fanback
     snap = engine.metrics.snapshot()
     out["rounds"] = snap["rounds"]
     out["cross_model_rounds"] = snap["cross_model_rounds"]
@@ -110,7 +125,7 @@ def main() -> None:
     # -- adaptive round planner end-to-end on the same mesh ----------------
     # composition choice is measurement-driven (calibrated wall-ms), so we
     # assert the machinery — every request served, strategies recorded,
-    # per-request fan-back still bitwise — not which composition won
+    # per-request fan-back still right — not which composition won
     cal2 = LatencyCalibrator(min_samples=2)
     adaptive = VisionServeEngine(
         reg, cost_model=SystolicCostModel(calibrator=cal2, n_devices=8,
@@ -122,13 +137,14 @@ def main() -> None:
     results2 = {r.rid: r for r in adaptive.flush()}
     ok2 = all(results2[rid].status == "ok" for rid in rids2)
     fanback2 = all(
-        np.array_equal(results2[rid].logits,
-                       np.asarray(ref.apply(k, fit_image(
-                           np.asarray(img, np.float32), 16)[None]))[0])
+        np.allclose(results2[rid].logits,
+                    np.asarray(ref.apply(k, fit_image(
+                        np.asarray(img, np.float32), 16)[None]))[0],
+                    **FANBACK_TOL)
         for rid, (k, img) in zip(rids2, items2))
     snap2 = adaptive.metrics.snapshot()
     out["adaptive_ok"] = bool(ok2)
-    out["adaptive_fanback_bitwise"] = bool(fanback2)
+    out["adaptive_fanback"] = bool(fanback2)
     out["adaptive_rounds"] = snap2["rounds"]
     out["adaptive_strategies"] = snap2["round_strategies"]
     out["adaptive_strategy_rounds_match"] = (

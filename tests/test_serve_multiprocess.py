@@ -23,6 +23,7 @@ import os
 import socket
 import subprocess
 import sys
+import tempfile
 import time
 
 import pytest
@@ -42,25 +43,44 @@ def _free_port():
     return port
 
 
-def _child_env(n_devices):
+def _child_env(n_devices, cache_dir):
+    """A CPU launcher child: the two-process mesh is a rehearsal of the
+    control plane on virtual CPU devices, never a second process on a
+    chip.  The cache directory reaches it through the environment."""
     env = dict(os.environ)
     env["PYTHONPATH"] = (os.path.join(ROOT, "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{n_devices}")
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir)
     env.pop("REPRO_NUM_PROCESSES", None)
     env.pop("REPRO_PROCESS_ID", None)
     env.pop("JAX_COORDINATOR_ADDRESS", None)
     return env
 
 
-def _launcher(extra, n_devices):
-    return subprocess.Popen(
+def _launcher(extra, n_devices, cache_dir):
+    logs = (tempfile.TemporaryFile("w+"), tempfile.TemporaryFile("w+"))
+    proc = subprocess.Popen(
         [sys.executable, "-m", "repro.launch.serve_vision",
          *COMMON, *extra],
-        env=_child_env(n_devices), cwd=ROOT,
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+        env=_child_env(n_devices, cache_dir), cwd=ROOT,
+        stdout=logs[0], stderr=logs[1], text=True)
+    proc.logs = logs
+    return proc
+
+
+def _communicate(proc, timeout):
+    """Wait for a launcher; return its (stdout, stderr).  Output goes to
+    files, not pipes: a pipe drained only after the other process of the
+    pair exits fills up (XLA logs a long line per cache load) and blocks
+    its writer, and the pair deadlocks."""
+    proc.wait(timeout=timeout)
+    out, err = proc.logs
+    out.seek(0)
+    err.seek(0)
+    return out.read(), err.read()
 
 
 @pytest.fixture(scope="module")
@@ -70,23 +90,21 @@ def mp_pair(tmp_path_factory):
     port = _free_port()
     pair = ["--mesh", "2", "--coordinator", f"127.0.0.1:{port}",
             "--num-processes", "2",
-            "--compilation-cache-dir", str(cache),
             "--warmup-manifest", str(base / "manifest.json")]
     coord = _launcher([*pair, "--process-id", "0",
-                       "--json", str(base / "coord.json")], 2)
+                       "--json", str(base / "coord.json")], 2, cache)
     time.sleep(1.0)   # the worker joins late; broadcasts queue for it
     worker = _launcher([*pair, "--process-id", "1",
-                        "--json", str(base / "worker.json")], 2)
-    cout, cerr = coord.communicate(timeout=900)
-    wout, werr = worker.communicate(timeout=900)
+                        "--json", str(base / "worker.json")], 2, cache)
+    cout, cerr = _communicate(coord, 900)
+    wout, werr = _communicate(worker, 900)
     assert coord.returncode == 0, (cout[-2000:], cerr[-4000:])
     assert worker.returncode == 0, (wout[-2000:], werr[-4000:])
 
     single = _launcher(["--mesh", "4",
-                        "--compilation-cache-dir",
-                        str(base / "jax_cache_single"),
-                        "--json", str(base / "single.json")], 4)
-    sout, serr = single.communicate(timeout=900)
+                        "--json", str(base / "single.json")], 4,
+                       base / "jax_cache_single")
+    sout, serr = _communicate(single, 900)
     assert single.returncode == 0, (sout[-2000:], serr[-4000:])
     return (json.loads((base / "coord.json").read_text()),
             json.loads((base / "worker.json").read_text()),

@@ -6,7 +6,7 @@ Two layers:
   planning, atomic round pops, round-drain admission estimates) that run
   in-process on the cost model and batcher alone;
 * device tests on 8 virtual CPU devices — bitwise parity of sharded vs
-  unsharded execution per backend, engine end-to-end round scheduling with
+  unsharded execution per backend (at the per-device batch shape), engine end-to-end round scheduling with
   result fan-back — which need ``--xla_force_host_platform_device_count``
   set before jax initializes, so they run once in a subprocess child
   (``tests/_serve_sharded_child.py``) whose JSON output the tests here
@@ -160,8 +160,10 @@ def test_child_saw_8_virtual_devices(sharded):
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
 def test_sharded_outputs_bitwise_match_unsharded(sharded, backend):
     """Acceptance: same backend, sharded (data-parallel over the mesh,
-    replicated when indivisible, half-mesh device group) vs unsharded —
-    bitwise equal."""
+    replicated when indivisible, half-mesh device group) vs unsharded at
+    the batch shape each device sees — bitwise equal.  (XLA may pick other
+    kernels for another batch size, so unsharded batch 8 vs eight
+    1-image shards is not a bitwise comparison.)"""
     assert sharded[f"parity_{backend}_b8"] is True
     assert sharded[f"parity_{backend}_b4"] is True
     assert sharded[f"parity_{backend}_group4"] is True
@@ -177,7 +179,7 @@ def test_engine_forms_cross_model_rounds_on_mesh(sharded):
 def test_engine_fans_results_back_in_order(sharded):
     assert sharded["e2e_statuses_ok"] is True
     assert sharded["e2e_rid_order"] is True
-    assert sharded["e2e_fanback_bitwise"] is True
+    assert sharded["e2e_fanback"] is True
 
 
 def test_round_jit_cache_is_bounded_and_calibration_sharded(sharded):
@@ -187,10 +189,10 @@ def test_round_jit_cache_is_bounded_and_calibration_sharded(sharded):
 
 def test_adaptive_planner_serves_on_mesh(sharded):
     """Adaptive composition scoring end-to-end on 8 devices: every request
-    ok, per-request fan-back bitwise, every dispatched round attributed to
+    ok, per-request fan-back to the right request, every dispatched round attributed to
     a scored strategy (which one wins is measurement-dependent)."""
     assert sharded["adaptive_ok"] is True
-    assert sharded["adaptive_fanback_bitwise"] is True
+    assert sharded["adaptive_fanback"] is True
     assert sharded["adaptive_rounds"] >= 1
     assert sharded["adaptive_strategy_rounds_match"] is True
     assert set(sharded["adaptive_strategies"]) <= {"even", "uneven", "serial"}

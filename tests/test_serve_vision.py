@@ -259,6 +259,35 @@ def test_engine_unknown_model_raises():
         engine.submit("nope/depthwise", np.zeros((32, 32, 3), np.float32))
 
 
+@pytest.mark.parametrize("poison", [False, True])
+def test_launcher_exit_status_follows_request_statuses(monkeypatch, tmp_path,
+                                                       capsys, poison):
+    """The launcher returns normally when every request is served and
+    exits non-zero when any request ends in status "error" (a batch whose
+    apply raised) — a failed run never looks like a passing one."""
+    from repro.launch import serve_vision as launcher
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    if poison:
+        real = ModelRegistry.apply
+
+        def apply(self, key, images, devices=None):
+            if np.any(np.asarray(images)):     # warmup batches are zeros
+                raise RuntimeError("poisoned batch")
+            return real(self, key, images, devices=devices)
+        monkeypatch.setattr(ModelRegistry, "apply", apply)
+    argv = ["--models", "tiny_net/fuse_full", "--resolution", "16",
+            "--requests", "2", "--buckets", "1", "2"]
+    if poison:
+        with pytest.raises(SystemExit) as exc:
+            launcher.main(argv)
+        assert exc.value.code not in (0, None)
+        assert "status 'error'" in str(exc.value.code)
+    else:
+        launcher.main(argv)
+    out = capsys.readouterr().out
+    assert (" error " in out) == poison
+
+
 def test_percentile_nearest_rank():
     assert percentile([], 50) == 0.0
     xs = [float(i) for i in range(1, 101)]
