@@ -83,9 +83,7 @@ def main():
           f"({m['completed']} completed, {m['batches']} batches, "
           f"{m['padded_slots']} padded slots)")
     print(f"pipeline: max_in_flight={m['max_in_flight']} "
-          f"overlap_ratio={m['overlap_ratio']:.2f} "
-          f"(host {m['host_busy_s']:.2f}s busy, "
-          f"device {m['device_busy_s']:.2f}s busy)")
+          f"(host {m['host_busy_s']:.2f}s busy forming batches)")
     print(f"calibration: {m['calibrated_batches']}/{m['batches']} batches "
           f"scheduled on calibrated wall-ms; |resid| p50="
           f"{m['calibration_abs_resid_ms']['p50_ms']:.2f}ms")

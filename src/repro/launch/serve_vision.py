@@ -36,6 +36,10 @@ logical universe; process 0 runs the scheduler and traffic, every other
 process runs the worker follower loop (no engine, no flags beyond the
 model set) and reports its stripe/warm-join accounting as its snapshot.
 See docs/serving_vision.md for the 2-process bring-up runbook.
+
+``--profiler-port PORT`` starts JAX's profiler server, so the running
+launcher can be traced from another shell with the engine's spans on the
+device trace's clock (docs/serving_vision.md, "Tracing a live server").
 """
 from __future__ import annotations
 
@@ -189,6 +193,11 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--json", dest="json_path", default=None,
                     help="write the metrics snapshot to this path")
+    ap.add_argument("--profiler-port", type=int, default=None,
+                    help="start jax.profiler's server on this port, so a"
+                         " trace can be captured while the launcher serves"
+                         " (docs/serving_vision.md, 'Tracing a live"
+                         " server')")
     args = ap.parse_args(argv)
 
     import os
@@ -291,6 +300,10 @@ def main(argv=None):
             raise SystemExit("--mesh needs the pipelined executor; "
                              "drop --sync / --engine sync")
         mesh = make_data_mesh(args.mesh)
+
+    if args.profiler_port is not None:
+        import jax
+        jax.profiler.start_server(args.profiler_port)
 
     registry = ModelRegistry(backend=args.backend, mesh=mesh,
                              compilation_cache_dir=cache_dir)

@@ -93,6 +93,7 @@ from __future__ import annotations
 
 import dataclasses
 import inspect
+import itertools
 import json
 import os
 import queue
@@ -111,7 +112,7 @@ from repro.serving.vision.calibrate import LatencyCalibrator
 from repro.serving.vision.compilecache import (counters_delta,
                                                persistent_cache_counters)
 from repro.serving.vision.costmodel import BucketPlan, SystolicCostModel
-from repro.serving.vision.metrics import ServeMetrics
+from repro.serving.vision.metrics import ServeMetrics, install_gc_span, span
 from repro.serving.vision.registry import (ModelRegistry, device_groups,
                                            device_groups_sized)
 from repro.serving.vision.tenancy import class_priority, class_weight
@@ -197,6 +198,7 @@ class _Prepared:
     devices: Optional[tuple] = None   # device group (round scheduler only)
     replanned: bool = False           # mid-flight backfill, not a round part
     group: Optional[int] = None       # round group index (readiness probing)
+    bid: int = 0                      # batch id: the ``batch`` stat of its spans
 
 
 @dataclasses.dataclass
@@ -211,6 +213,7 @@ class _Round:
     n_groups: int
     groups: Optional[List[Optional[tuple]]] = None
     group_ms: Optional[List[float]] = None
+    bid: int = 0                      # batch id, shared by its parts
 
 
 @dataclasses.dataclass
@@ -335,6 +338,10 @@ class VisionServeEngine:
         self._closed = False
         self._drain_on_close = True
         self._flush_waiters = 0        # flush() intent: stop coalescing
+        # batch ids, from formation to fan-back: the ``batch`` stat of the
+        # spans (docs/serving_vision.md, "Tracing a live server")
+        self._batch_ids = itertools.count()
+        install_gc_span()
 
     # -- intake -------------------------------------------------------------
     def submit(self, model_key: str, image: np.ndarray,
@@ -363,48 +370,49 @@ class VisionServeEngine:
         with self._lock:
             rid = self._next_rid
             self._next_rid += 1
-        self.metrics.on_submit()
-        if slo_ms is not None:
-            admitted, predicted = self._admit(model, model_key, slo_ms)
-            if not admitted and self._shed:
-                # evict strictly-lower-priority queued work until this
-                # request fits (or nothing lower remains); every eviction
-                # changes the backlog, so admission is re-priced each time
-                while not admitted:
-                    victim = self._queue.shed_lowest(cls.priority,
-                                                     class_priority)
-                    if victim is None:
-                        break
-                    self._resolve_shed(victim)
-                    admitted, predicted = self._admit(model, model_key,
-                                                      slo_ms)
-            if not admitted:
-                self.metrics.on_reject()
-                res = VisionResult(rid, model_key, "rejected", None,
-                                   predicted, slo_class=cls.name,
-                                   tenant=tenant)
-                fut = VisionFuture(rid)
-                fut._resolve(res)
-                with self._lock:
-                    self._results[rid] = res
-                    self._futures[rid] = fut
-                return rid
-        if self.pipelined:
-            self._ensure_started()
-        with self._work_cv:
-            # re-check under the lock close() takes to flip _closing: a
-            # request pushed here is either seen by the draining scheduler
-            # or swept by close()'s cancel pass — never stranded
-            if self._closing or self._closed:
-                raise RuntimeError("engine is closed")
-            self._futures[rid] = VisionFuture(rid)
-            self._queue.push(VisionRequest(rid, model_key,
-                                           np.asarray(image),
-                                           self._clock(), slo_ms,
-                                           slo_class=cls.name,
-                                           tenant=tenant))
-            self._work_cv.notify_all()
-        return rid
+        with span("vision.admit", rid=rid, model=model_key):
+            self.metrics.on_submit()
+            if slo_ms is not None:
+                admitted, predicted = self._admit(model, model_key, slo_ms)
+                if not admitted and self._shed:
+                    # evict strictly-lower-priority queued work until this
+                    # request fits (or nothing lower remains); every eviction
+                    # changes the backlog, so admission is re-priced each time
+                    while not admitted:
+                        victim = self._queue.shed_lowest(cls.priority,
+                                                         class_priority)
+                        if victim is None:
+                            break
+                        self._resolve_shed(victim)
+                        admitted, predicted = self._admit(model, model_key,
+                                                          slo_ms)
+                if not admitted:
+                    self.metrics.on_reject()
+                    res = VisionResult(rid, model_key, "rejected", None,
+                                       predicted, slo_class=cls.name,
+                                       tenant=tenant)
+                    fut = VisionFuture(rid)
+                    fut._resolve(res)
+                    with self._lock:
+                        self._results[rid] = res
+                        self._futures[rid] = fut
+                    return rid
+            if self.pipelined:
+                self._ensure_started()
+            with self._work_cv:
+                # re-check under the lock close() takes to flip _closing: a
+                # request pushed here is either seen by the draining scheduler
+                # or swept by close()'s cancel pass — never stranded
+                if self._closing or self._closed:
+                    raise RuntimeError("engine is closed")
+                self._futures[rid] = VisionFuture(rid)
+                self._queue.push(VisionRequest(rid, model_key,
+                                               np.asarray(image),
+                                               self._clock(), slo_ms,
+                                               slo_class=cls.name,
+                                               tenant=tenant))
+                self._work_cv.notify_all()
+            return rid
 
     def _admit(self, model, model_key: str,
                slo_ms: float) -> Tuple[bool, float]:
@@ -516,24 +524,28 @@ class VisionServeEngine:
                         if self._queue.pending() == 0:
                             if self._closing:
                                 break
-                            self._work_cv.wait(timeout=0.05)
+                            with span("vision.await_work"):
+                                self._work_cv.wait(timeout=0.05)
                     continue
                 if self._closing and not self._drain_on_close:
                     break
                 pick = self._pick_model()
                 if pick is None:        # sub-maximal batches inside window
-                    with self._work_cv:
+                    with self._work_cv, span("vision.await_work"):
                         self._work_cv.wait(
                             timeout=min(self.batch_window_ms / 1e3, 0.05))
                     continue
                 model_key, depth = pick
                 # reserve an in-flight slot before touching the queue; gives
                 # up only on a no-drain close so shutdown can't wedge here
-                acquired = self._depth_sem.acquire(timeout=0.05)
-                while not acquired:
-                    if self._closing and not self._drain_on_close:
-                        break
-                    acquired = self._depth_sem.acquire(timeout=0.05)
+                acquired = self._depth_sem.acquire(blocking=False)
+                if not acquired:
+                    with span("vision.await_slot"):
+                        acquired = self._depth_sem.acquire(timeout=0.05)
+                        while not acquired:
+                            if self._closing and not self._drain_on_close:
+                                break
+                            acquired = self._depth_sem.acquire(timeout=0.05)
                 if not acquired:
                     break
                 if self._closing and not self._drain_on_close:
@@ -548,36 +560,40 @@ class VisionServeEngine:
                         self._submit_q.put(item)       # backpressure
                     continue
                 model = self.registry.get(model_key)
-                t_h0 = self._clock()
-                try:
-                    plan = self.cost_model.plan_bucket(model, depth,
-                                                       self.buckets)
-                except Exception as exc:
-                    # cost-model failure: fail this model's queued requests
-                    # rather than retrying the same exception forever.  Same
-                    # invariant as the happy path: count the batch in flight
-                    # BEFORE popping so a concurrent flush() can't observe
-                    # an empty queue with nothing in flight mid-failure.
+                bid = next(self._batch_ids)
+                with self.metrics.stage("vision.form_batch", batch=bid,
+                                        model=model_key) as stage:
+                    try:
+                        plan = self.cost_model.plan_bucket(model, depth,
+                                                           self.buckets)
+                    except Exception as exc:
+                        # cost-model failure: fail this model's queued
+                        # requests rather than retrying the same exception
+                        # forever.  Same invariant as the happy path: count
+                        # the batch in flight BEFORE popping so a concurrent
+                        # flush() can't observe an empty queue with nothing
+                        # in flight mid-failure.
+                        with self._lock:
+                            self._inflight_batches += 1
+                        self.metrics.on_inflight(+1)
+                        self._fail(self._queue.pop(model_key, depth), None,
+                                   exc, in_flight=True)
+                        continue
                     with self._lock:
+                        # counted BEFORE the pop so flush never observes an
+                        # empty queue while a batch is being formed
                         self._inflight_batches += 1
+                        self._inflight_pred_ms += plan.predicted_ms
                     self.metrics.on_inflight(+1)
-                    self._fail(self._queue.pop(model_key, depth), None, exc,
-                               in_flight=True)
-                    continue
-                with self._lock:
-                    # counted BEFORE the pop so flush never observes an
-                    # empty queue while a batch is being formed
-                    self._inflight_batches += 1
-                    self._inflight_pred_ms += plan.predicted_ms
-                self.metrics.on_inflight(+1)
-                reqs = self._queue.pop(model_key, plan.served)
-                try:
-                    batch = form_batch(reqs, plan.bucket, model.resolution)
-                    self.metrics.on_stage("host", self._clock() - t_h0)
-                except Exception as exc:
-                    self._fail(reqs, plan, exc, in_flight=True)
-                    continue
-                self._submit_q.put(_Prepared(batch, plan))  # backpressure
+                    reqs = self._queue.pop(model_key, plan.served)
+                    stage.set_metadata(bucket=plan.bucket, fill=len(reqs))
+                    try:
+                        batch = form_batch(reqs, plan.bucket,
+                                           model.resolution)
+                    except Exception as exc:
+                        self._fail(reqs, plan, exc, in_flight=True)
+                        continue
+                self._submit_q.put(_Prepared(batch, plan, bid=bid))
         finally:
             self._submit_q.put(_STOP)
 
@@ -591,62 +607,67 @@ class VisionServeEngine:
             self._depth_sem.release()
             return None
         models = [(self.registry.get(m), d) for m, d, _ in entries]
-        t_h0 = self._clock()
-        try:
-            plan_kw = {}
-            weights = self._queue.class_weights(class_weight)
-            if any(w != 1.0 for w in weights.values()) \
-                    and self._planner_takes_weights():
-                # mixed service classes queued: let the planner weigh
-                # ms-per-served-request by class priority (tenancy.py)
-                plan_kw["weights"] = weights
-            rplan = self.cost_model.plan_round(models, self.buckets,
-                                               **plan_kw)
-            # resolved before any request is popped: a plan whose group
-            # count can't partition the device list must fail HERE, where
-            # containment below still owns every queued request
-            sizes = getattr(rplan, "group_sizes", None)
-            if self._devices is None:
-                groups = [None] * rplan.n_groups
-            elif sizes is not None:
-                # adaptive plans carry explicit (possibly uneven) sizes
-                groups = device_groups_sized(self._devices, sizes)
-            else:
-                groups = device_groups(self._devices, rplan.n_groups)
-        except Exception as exc:
-            # planner failure: fail everything currently queued rather than
-            # retrying the same exception forever (same invariant as the
-            # single-model path: count in flight BEFORE popping)
+        bid = next(self._batch_ids)
+        with self.metrics.stage("vision.form_round", batch=bid) as stage:
+            try:
+                plan_kw = {}
+                weights = self._queue.class_weights(class_weight)
+                if any(w != 1.0 for w in weights.values()) \
+                        and self._planner_takes_weights():
+                    # mixed service classes queued: let the planner weigh
+                    # ms-per-served-request by class priority (tenancy.py)
+                    plan_kw["weights"] = weights
+                rplan = self.cost_model.plan_round(models, self.buckets,
+                                                   **plan_kw)
+                # resolved before any request is popped: a plan whose group
+                # count can't partition the device list must fail HERE,
+                # where containment below still owns every queued request
+                sizes = getattr(rplan, "group_sizes", None)
+                if self._devices is None:
+                    groups = [None] * rplan.n_groups
+                elif sizes is not None:
+                    # adaptive plans carry explicit (possibly uneven) sizes
+                    groups = device_groups_sized(self._devices, sizes)
+                else:
+                    groups = device_groups(self._devices, rplan.n_groups)
+            except Exception as exc:
+                # planner failure: fail everything currently queued rather
+                # than retrying the same exception forever (same invariant
+                # as the single-model path: count in flight BEFORE popping)
+                with self._lock:
+                    self._inflight_batches += 1
+                self.metrics.on_inflight(+1)
+                reqs = [r for m, d, _ in entries
+                        for r in self._queue.pop(m, d)]
+                self._fail(reqs, None, exc, in_flight=True)
+                return None
             with self._lock:
+                # counted BEFORE the atomic pop so flush never observes an
+                # empty queue while the round is being formed
                 self._inflight_batches += 1
+                self._inflight_pred_ms += rplan.predicted_ms
             self.metrics.on_inflight(+1)
-            reqs = [r for m, d, _ in entries for r in self._queue.pop(m, d)]
-            self._fail(reqs, None, exc, in_flight=True)
-            return None
-        with self._lock:
-            # counted BEFORE the atomic pop so flush never observes an
-            # empty queue while the round is being formed
-            self._inflight_batches += 1
-            self._inflight_pred_ms += rplan.predicted_ms
-        self.metrics.on_inflight(+1)
-        pops = self._queue.pop_many([(p.key, p.plan.served)
-                                     for p in rplan.parts])
-        formed = form_round(
-            [(reqs, part.plan.bucket, self.registry.get(part.key).resolution)
-             for part, reqs in zip(rplan.parts, pops)])
-        parts: List[_Prepared] = []
-        for part, reqs, batch in zip(rplan.parts, pops, formed):
-            if batch is None:
-                continue
-            if isinstance(batch, BaseException):
-                # a malformed part must not sink the whole round: fail its
-                # requests, keep the others (round slot released at the end)
-                self._fail(reqs, part.plan, batch, in_flight=False)
-                continue
-            parts.append(_Prepared(batch, part.plan,
-                                   devices=groups[part.group],
-                                   group=part.group))
-        self.metrics.on_stage("host", self._clock() - t_h0)
+            pops = self._queue.pop_many([(p.key, p.plan.served)
+                                         for p in rplan.parts])
+            formed = form_round(
+                [(reqs, part.plan.bucket,
+                  self.registry.get(part.key).resolution)
+                 for part, reqs in zip(rplan.parts, pops)])
+            parts: List[_Prepared] = []
+            for part, reqs, batch in zip(rplan.parts, pops, formed):
+                if batch is None:
+                    continue
+                if isinstance(batch, BaseException):
+                    # a malformed part must not sink the whole round: fail
+                    # its requests, keep the others (round slot released at
+                    # the end)
+                    self._fail(reqs, part.plan, batch, in_flight=False)
+                    continue
+                parts.append(_Prepared(batch, part.plan,
+                                       devices=groups[part.group],
+                                       group=part.group, bid=bid))
+            stage.set_metadata(parts=len(parts), groups=rplan.n_groups,
+                               fill=sum(p.batch.fill for p in parts))
         if not parts:
             self._round_done(rplan.predicted_ms)
             return None
@@ -656,7 +677,7 @@ class VisionServeEngine:
                               group_sizes=getattr(rplan, "group_sizes", None))
         return _Round(parts, rplan.predicted_ms, rplan.n_groups,
                       groups=list(groups),
-                      group_ms=getattr(rplan, "group_ms", None))
+                      group_ms=getattr(rplan, "group_ms", None), bid=bid)
 
     def _planner_takes_weights(self) -> bool:
         """Whether the cost model's plan_round accepts the tenancy
@@ -750,9 +771,11 @@ class VisionServeEngine:
                         exhausted.add(gi)
                         continue
                     try:
-                        logits = self.registry.apply(prep.batch.model,
-                                                     prep.batch.images,
-                                                     devices=prep.devices)
+                        with span("vision.dispatch", batch=prep.bid,
+                                  bucket=prep.batch.bucket, group=gi):
+                            logits = self.registry.apply(
+                                prep.batch.model, prep.batch.images,
+                                devices=prep.devices)
                     except Exception as exc:
                         logits = _BatchError(exc)
                     outs.append((prep, logits, self._clock()))
@@ -804,7 +827,7 @@ class VisionServeEngine:
                 self._fail(reqs, plan, exc, in_flight=False)
                 continue
             return _Prepared(batch, plan, devices=group, replanned=True,
-                             group=group_index)
+                             group=group_index, bid=next(self._batch_ids))
         return None
 
     def _is_warm(self, model_key: str, bucket: int,
@@ -849,24 +872,30 @@ class VisionServeEngine:
                             continue
                     for idx, p in enumerate(item.parts):
                         try:
-                            if mp_round is not None:
-                                logits = self.multiprocess.dispatch(
-                                    mp_round, idx, p.batch.model,
-                                    p.batch.images, p.devices)
-                            else:
-                                logits = self.registry.apply(
-                                    p.batch.model, p.batch.images,
-                                    devices=p.devices)
+                            with span("vision.dispatch", batch=p.bid,
+                                      bucket=p.batch.bucket,
+                                      group=p.group or 0):
+                                if mp_round is not None:
+                                    logits = self.multiprocess.dispatch(
+                                        mp_round, idx, p.batch.model,
+                                        p.batch.images, p.devices)
+                                else:
+                                    logits = self.registry.apply(
+                                        p.batch.model, p.batch.images,
+                                        devices=p.devices)
                         except Exception as exc:
                             logits = _BatchError(exc)
                         outs.append((p, logits, self._clock()))
                     if self.replan:
-                        self._replan_round(item, outs, t0)
+                        with span("vision.replan", batch=item.bid):
+                            self._replan_round(item, outs, t0)
                     self._complete_q.put((item, outs, t0))
                     continue
                 try:
-                    logits = self.registry.apply(item.batch.model,
-                                                 item.batch.images)
+                    with span("vision.dispatch", batch=item.bid,
+                              bucket=item.batch.bucket, group=0):
+                        logits = self.registry.apply(item.batch.model,
+                                                     item.batch.images)
                 except Exception as exc:
                     logits = _BatchError(exc)
                 self._complete_q.put((item, logits, t0))
@@ -887,16 +916,17 @@ class VisionServeEngine:
                 # a multiprocess PartHandle blocks on the local stripe AND
                 # gathers worker shards; plain outputs block on the device
                 mat = getattr(logits, "materialize", None)
-                logits = (mat() if mat is not None
-                          else jax.block_until_ready(logits))
+                with span("vision.await_device", batch=p.bid):
+                    logits = (mat() if mat is not None
+                              else jax.block_until_ready(logits))
                 t1 = self._clock()
-                self._finalize(p, np.asarray(logits), t_disp, t1,
-                               in_flight=False,
-                               service_start=max(t_disp, t_start))
+                with span("vision.fanback", batch=p.bid, fill=p.batch.fill):
+                    self._finalize(p, np.asarray(logits), t_disp, t1,
+                                   in_flight=False,
+                                   service_start=max(t_disp, t_start))
             except Exception as exc:
                 self._fail(p.batch.requests, p.plan, exc, in_flight=False)
         t_end = self._clock()
-        self.metrics.on_stage("device", t_end - t_start)
         # composition feedback: how far off was the chosen plan's round
         # latency from what the mesh actually delivered?
         self.metrics.on_round_complete(rnd.predicted_ms,
@@ -917,7 +947,8 @@ class VisionServeEngine:
             try:
                 if isinstance(logits, _BatchError):
                     raise logits.exc
-                logits = jax.block_until_ready(logits)
+                with span("vision.await_device", batch=item.bid):
+                    logits = jax.block_until_ready(logits)
                 t1 = self._clock()
                 # service time, not dispatch-to-ready: under pipelining this
                 # batch was dispatched while its predecessor still occupied
@@ -926,9 +957,10 @@ class VisionServeEngine:
                 # (and calibrated) latency double-counts device time
                 t_start = t0 if t_prev is None else max(t0, t_prev)
                 t_prev = t1
-                self.metrics.on_stage("device", t1 - t_start)
-                self._finalize(item, np.asarray(logits), t0, t1,
-                               in_flight=True, service_start=t_start)
+                with span("vision.fanback", batch=item.bid,
+                          fill=item.batch.fill):
+                    self._finalize(item, np.asarray(logits), t0, t1,
+                                   in_flight=True, service_start=t_start)
             except Exception as exc:
                 # the failed batch still consumed device timeline up to now;
                 # advance t_prev so the next batch isn't charged for it
@@ -1232,15 +1264,20 @@ class VisionServeEngine:
             return []
         model_key, depth, _ = snap
         model = self.registry.get(model_key)
-        t_h0 = self._clock()
-        plan = self.cost_model.plan_bucket(model, depth, self.buckets)
-        reqs = self._queue.pop(model_key, plan.served)
-        batch = form_batch(reqs, plan.bucket, model.resolution)
-        self.metrics.on_stage("host", self._clock() - t_h0)
+        bid = next(self._batch_ids)
+        with self.metrics.stage("vision.form_batch", batch=bid,
+                                model=model_key) as stage:
+            plan = self.cost_model.plan_bucket(model, depth, self.buckets)
+            reqs = self._queue.pop(model_key, plan.served)
+            stage.set_metadata(bucket=plan.bucket, fill=len(reqs))
+            batch = form_batch(reqs, plan.bucket, model.resolution)
         t0 = self._clock()
         try:
-            logits = self.registry.apply(model_key, batch.images)
-            logits = jax.block_until_ready(logits)
+            with span("vision.dispatch", batch=bid, bucket=plan.bucket,
+                      group=0):
+                logits = self.registry.apply(model_key, batch.images)
+            with span("vision.await_device", batch=bid):
+                logits = jax.block_until_ready(logits)
         except Exception as exc:
             # engine-interface conformance: a poisoned batch resolves its
             # requests with status "error" on every engine — the pipelined
@@ -1249,9 +1286,10 @@ class VisionServeEngine:
             self._fail(reqs, plan, exc, in_flight=False)
             return []
         t1 = self._clock()
-        self.metrics.on_stage("device", t1 - t0)
-        return self._finalize(_Prepared(batch, plan), np.asarray(logits),
-                              t0, t1, in_flight=False)
+        with span("vision.fanback", batch=bid, fill=batch.fill):
+            return self._finalize(_Prepared(batch, plan, bid=bid),
+                                  np.asarray(logits), t0, t1,
+                                  in_flight=False)
 
     def flush(self) -> List[VisionResult]:
         """Wait for all queued work to complete (pipelined) or drain it on

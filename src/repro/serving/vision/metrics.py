@@ -1,7 +1,12 @@
-"""Serving metrics: counters + latency distributions (p50/p99, throughput).
+"""Serving metrics: counters + latency distributions (p50/p99, throughput),
+and the engine's profiler spans.
 
-Pure-Python accounting (no jax): every number here is host-side bookkeeping
-around the jitted compute, so importing this module never touches a device.
+Counters are pure-Python host bookkeeping around the jitted compute.  The
+spans (``span``, ``ServeMetrics.stage``, ``install_gc_span``) are
+``jax.profiler.TraceAnnotation``s, imported lazily: they record nothing
+unless a profiler session is active with its host tracer at level 1 or
+more, and then land on the session's clock beside the device's events.
+Importing this module never touches a device.
 
 Units: every ``*_ms`` here is measured **wall milliseconds** on the
 engine's clock, and every ``*_s`` wall seconds — with one deliberate
@@ -18,16 +23,17 @@ under mixed bucket sizes reflects what requests actually experienced (a
 bucket-8 batch carries 8x the weight of a singleton).  Batch-level counts
 (batches, padded slots, cost-model error) stay per-batch.
 
-The pipelined engine additionally reports stage-occupancy numbers: current
-and peak in-flight batch depth, per-stage busy seconds, and an overlap
-ratio (how much of the device stage's busy time was hidden behind host-side
-batching) derived as ``(host_busy + device_busy - wall) / device_busy``,
-clamped to [0, 1].  All mutators take one lock — submit, scheduler, and
-completion threads all write here.
+The pipelined engine additionally reports pipeline occupancy (current and
+peak in-flight batch depth) and ``host_busy_s``, the seconds its host
+stage spent planning and forming batches, which the same ``stage`` that
+spans that work adds up.  All mutators take one lock — submit, scheduler,
+and completion threads all write here.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import gc
 import random
 import threading
 import time
@@ -43,6 +49,48 @@ def percentile(values: List[float], p: float) -> float:
     xs = sorted(values)
     rank = max(0, min(len(xs) - 1, int(round(p / 100.0 * (len(xs) - 1)))))
     return xs[rank]
+
+
+def span(name: str, **stats):
+    """A profiler span (``jax.profiler.TraceAnnotation``) named ``name``
+    with ``stats`` as its stats; use as a context manager, or call
+    ``set_metadata(**stats)`` on it to add stats known only later.  The
+    engine's spans are named ``vision.<stage>``; docs/serving_vision.md
+    lists them."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name, **stats)
+
+
+class _GcSpan:
+    """Spans each full (generation 2) garbage collection as
+    ``python.gc_full`` with the count it ``collected``: the collector
+    stops every thread of the process while it runs.  Start and stop fire
+    on the collecting thread, so the span lands on that thread."""
+
+    def __init__(self):
+        self._open = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if info["generation"] != 2:
+            return
+        if phase == "start":
+            self._open = span("python.gc_full")
+            self._open.__enter__()
+        elif self._open is not None:
+            self._open.set_metadata(collected=info["collected"])
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+
+_gc_lock = threading.Lock()
+
+
+def install_gc_span() -> None:
+    """Add the ``python.gc_full`` span to ``gc.callbacks``, once per
+    process (idempotent)."""
+    with _gc_lock:
+        if not any(isinstance(cb, _GcSpan) for cb in gc.callbacks):
+            gc.callbacks.append(_GcSpan())
 
 
 @dataclasses.dataclass
@@ -115,7 +163,6 @@ class ServeMetrics:
         self.in_flight = 0
         self.max_in_flight = 0
         self.host_busy_s = 0.0         # scheduling + letterbox/batch formation
-        self.device_busy_s = 0.0       # dispatch -> block_until_ready
         # cross-model round scheduler
         self.rounds = 0                # co-scheduled device rounds dispatched
         self.cross_model_rounds = 0    # rounds carrying >1 model
@@ -331,14 +378,19 @@ class ServeMetrics:
             self.in_flight += delta
             self.max_in_flight = max(self.max_in_flight, self.in_flight)
 
-    def on_stage(self, stage: str, seconds: float) -> None:
-        with self._lock:
-            if stage == "host":
-                self.host_busy_s += seconds
-            elif stage == "device":
-                self.device_busy_s += seconds
-            else:
-                raise ValueError(stage)
+    @contextlib.contextmanager
+    def stage(self, name: str, **stats):
+        """The host stage's span: ``span(name, **stats)``, whose duration
+        on this object's clock is added to ``host_busy_s``.  Yields the
+        span (``set_metadata`` adds stats known only later)."""
+        with span(name, **stats) as sp:
+            t0 = self._clock()
+            try:
+                yield sp
+            finally:
+                dt = self._clock() - t0
+                with self._lock:
+                    self.host_busy_s += dt
 
     @property
     def wall_s(self) -> float:
@@ -351,15 +403,6 @@ class ServeMetrics:
         """Completed images per wall-clock second (0 until a batch ran)."""
         wall = self.wall_s
         return self.completed / wall if wall > 0 else 0.0
-
-    @property
-    def overlap_ratio(self) -> float:
-        """Fraction of device busy time overlapped with host-stage work."""
-        wall = self.wall_s
-        if self.device_busy_s <= 0.0 or wall <= 0.0:
-            return 0.0
-        overlap = self.host_busy_s + self.device_busy_s - wall
-        return max(0.0, min(1.0, overlap / self.device_busy_s))
 
     def snapshot(self) -> Dict:
         with self._lock:
@@ -407,8 +450,6 @@ class ServeMetrics:
                 },
                 "max_in_flight": self.max_in_flight,
                 "host_busy_s": self.host_busy_s,
-                "device_busy_s": self.device_busy_s,
-                "overlap_ratio": self.overlap_ratio,
                 "e2e": {m: s.summary() for m, s in self.e2e.items()},
                 "run": {m: s.summary() for m, s in self.run.items()},
                 "cost_model_abs_err_ms": self.cost_model_err.summary(),

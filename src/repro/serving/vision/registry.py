@@ -56,6 +56,7 @@ from repro.kernels import backend as kb
 from repro.serving.vision.compilecache import (counters_delta,
                                                enable_compilation_cache,
                                                persistent_cache_counters)
+from repro.serving.vision.metrics import span
 from repro.vision import zoo
 
 
@@ -219,23 +220,28 @@ class ModelRegistry:
         """Invoke a jit entry; the FIRST call per cache key is timed and
         logged (tracing + XLA compile happen inside it — with a persistent
         cache hit the same call deserializes from disk instead, and the
-        hit/miss delta captured around it records which one happened)."""
+        hit/miss delta captured around it records which one happened),
+        inside a ``vision.compile`` span."""
         with self._compile_lock:
             fresh = cache_key not in self._called
             if fresh:
                 self._called.add(cache_key)
         if not fresh:
             return fn(params, x)
-        before = persistent_cache_counters()
-        t0 = time.perf_counter()
-        out = fn(params, x)
-        build_ms = (time.perf_counter() - t0) * 1e3
-        delta = counters_delta(before)
+        devices = list(cache_key[2]) if len(cache_key) > 2 else None
+        with span("vision.compile", model=cache_key[0], bucket=cache_key[1],
+                  devices=" ".join(map(str, devices or ()))) as sp:
+            before = persistent_cache_counters()
+            t0 = time.perf_counter()
+            out = fn(params, x)
+            build_ms = (time.perf_counter() - t0) * 1e3
+            delta = counters_delta(before)
+            sp.set_metadata(pcache_hit=int(delta["hits"] > 0))
         with self._compile_lock:
             self._compile_log.append({
                 "entry": cache_key,
                 "key": cache_key[0], "bucket": cache_key[1],
-                "devices": list(cache_key[2]) if len(cache_key) > 2 else None,
+                "devices": devices,
                 "build_ms": build_ms,
                 "pcache_hits": int(delta["hits"]),
                 "pcache_misses": int(delta["misses"]),
