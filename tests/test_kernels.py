@@ -32,6 +32,11 @@ def test_fuse1d_sweep(n, t, c, k, dtype):
 @pytest.mark.parametrize("m,k,n", [
     (16, 16, 16), (128, 128, 128), (130, 257, 65), (7, 300, 5),
     (256, 64, 512),
+    # blocks from the shapes (matmul_plan): whole N that is no multiple of
+    # 128, M with an odd factor in one block or in row tiles that divide
+    # it, K too wide for one block (padded, accumulated), N split in two
+    (49, 160, 960), (1568, 24, 144), (6272, 96, 576), (1568, 1920, 160),
+    (49, 1000, 1280), (8, 256, 8320),
 ])
 def test_matmul_sweep(m, k, n):
     a = jax.random.normal(KEY, (m, k))

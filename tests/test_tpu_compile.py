@@ -93,10 +93,20 @@ def test_fuse1d_compiles(one_chip):
              one_chip, (8 * 28, 28 + 4, 120), (5, 120))
 
 
-def test_matmul_compiles(one_chip):
-    # a 1x1 expand at 56x56, batch 8: (B*H*W, Cin) @ (Cin, Cout)
+@pytest.mark.parametrize("m,k,n", [
+    (8 * 56 * 56, 24, 72),          # a 1x1 expand at 56x56, batch 8
+    # the widest 1x1s at bucket 32, blocks from matmul_plan
+    (32 * 112 * 112, 16, 96),       # MobileNetV2's first expand
+    (32 * 56 * 56, 24, 144),        # its 56x56 expand, N no multiple of 128
+    (32 * 7 * 7, 1920, 160),        # V3-large's widest project: 784-row
+                                    # whole-K blocks ran out of VMEM
+    (32 * 7 * 7, 320, 1280),        # MobileNetV2's head 1x1
+    (32 * 7 * 7, 1024, 1024),       # MobileNetV1's last 1x1: K split in two
+])
+def test_matmul_compiles(one_chip, m, k, n):
+    # (B*H*W, Cin) @ (Cin, Cout)
     _compile(functools.partial(kmatmul.matmul, interpret=False),
-             one_chip, (8 * 56 * 56, 24), (24, 72))
+             one_chip, (m, k), (k, n))
 
 
 def test_network_compiles_at_batch_8(one_chip):
